@@ -33,6 +33,7 @@ from repro.core import FusionSpec, build_index
 from repro.core.fusion import FUSION_MODES, PathStats
 from repro.core.search import SearchParams, search
 from repro.obs.metrics import GLOBAL
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.data.corpus import ndcg_at_k
 
 
@@ -150,6 +151,7 @@ def run_fusion_sweep(dry_run: bool = False) -> dict:
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--dry-run", action="store_true",

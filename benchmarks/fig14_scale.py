@@ -57,6 +57,7 @@ from repro.core.usms import PathWeights
 from repro.data.syncorpus import SynCorpus, SynCorpusConfig
 from repro.ingest import IngestPipeline
 from repro.obs.metrics import MetricsRegistry
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.batcher import BatcherConfig, _next_pow2
 from repro.serving.hybrid_service import HybridSearchService, ServiceConfig
 from repro.serving.replica_router import (
@@ -170,7 +171,9 @@ def build_tier(
     for i in range(n_replicas):
         _flush(i, final=True)
 
-    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    # replica i lives on device i (round-robin when replicas outnumber
+    # devices): replicas share nothing, so each gets its own chip
+    devices = jax.devices()
     replicas = []
     for i, pool in enumerate(pools):
         if pool is None:
@@ -178,6 +181,7 @@ def build_tier(
                 f"replica {i} received no docs — corpus too small for "
                 f"{n_replicas} replicas"
             )
+        mesh = Mesh(np.asarray([devices[i % len(devices)]]), ("data",))
         pool = place_pool(pool, mesh)
         svc = HybridSearchService(
             pool,
@@ -352,6 +356,7 @@ def run(
 
 
 def main() -> None:
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -35,6 +35,7 @@ from repro.core.usms import PathWeights
 from repro.data.corpus import recall_at_k
 from repro.data.textcorpus import load_bundled_corpus, topic_truth
 from repro.ingest import IngestConfig, IngestPipeline
+from repro.runtime.compile_cache import enable_compile_cache
 
 WEIGHTS = [
     ("dense_only", PathWeights.make(1, 0, 0)),
@@ -102,6 +103,7 @@ def run(dry_run: bool = False) -> dict:
 
 
 def main() -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--dry-run", action="store_true",

@@ -38,7 +38,8 @@ import jax.numpy as jnp
 from repro.core.usms import quantize_corpus
 from repro.kernels import ops, ref
 from repro.kernels.fused_topk import k_pad
-from repro.launch.hlo_analysis import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.hlo_analysis import MODELED_CHIP, chip_peaks
+from repro.runtime.compile_cache import enable_compile_cache
 from tests.helpers import random_fused
 
 from benchmarks.common import timed
@@ -94,8 +95,9 @@ def _bytes_model(*, b, c, dd, ps, pf, k, c_tile, bpe):
 def _roofline(*, b, c, dd, ps, pf, c_tile, bytes_fused):
     c_pad = -(-c // c_tile) * c_tile
     flops = b * c_pad * (2 * dd + 3 * ps * ps + 3 * pf * pf)
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = bytes_fused / HBM_BW
+    peaks = chip_peaks(MODELED_CHIP)
+    compute_s = flops / peaks.flops_bf16
+    memory_s = bytes_fused / peaks.hbm_bw
     return {
         "model_flops": flops,
         "compute_s": compute_s,
@@ -291,6 +293,7 @@ def run_quantized_recall() -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--dry-run", action="store_true",

@@ -8,7 +8,12 @@ import gzip
 import json
 import pathlib
 
-from repro.launch.hlo_analysis import analyze_hlo, roofline_terms
+from repro.launch.hlo_analysis import (
+    MODELED_CHIP,
+    analyze_hlo,
+    chip_peaks,
+    roofline_terms,
+)
 
 
 def reanalyze(json_path: pathlib.Path) -> bool:
@@ -30,6 +35,7 @@ def reanalyze(json_path: pathlib.Path) -> bool:
         hlo_bytes=hlo["hbm_bytes"],
         coll_bytes_per_device=hlo["collective_bytes"],
         n_chips=record["n_devices"],
+        peaks=chip_peaks(MODELED_CHIP),
     )
     json_path.write_text(json.dumps(record, indent=1))
     return True

@@ -14,7 +14,7 @@ if __package__ in (None, ""):  # script mode: python benchmarks/roofline.py
     _root = pathlib.Path(__file__).resolve().parents[1]
     sys.path[:0] = [str(_root), str(_root / "src")]
 
-from repro.launch.hlo_analysis import PEAK_FLOPS_BF16
+from repro.launch.hlo_analysis import MODELED_CHIP, chip_peaks
 
 KERNEL_BENCH = "results/BENCH_kernel.json"
 
@@ -43,8 +43,7 @@ def load_kernel_rows(path: str = KERNEL_BENCH) -> list[dict]:
         return []
     bench = json.loads(p.read_text())
     rows = []
-    from repro.launch.hlo_analysis import HBM_BW
-
+    hbm_bw = chip_peaks(MODELED_CHIP).hbm_bw
     for name, row in bench.get("sweep", {}).items():
         rl = row.get("roofline", {})
         util = row.get("model", {}).get("lane_util_candidates", 1.0)
@@ -66,7 +65,7 @@ def load_kernel_rows(path: str = KERNEL_BENCH) -> list[dict]:
         # shows how far dequant-in-tile moves the memory bound
         bq = row.get("model", {}).get("bytes_quantized")
         if bq is not None:
-            mem_q = bq / HBM_BW
+            mem_q = bq / hbm_bw
             comp = rl.get("compute_s", 0.0)
             rows.append({
                 "arch": "allanpoe-retrieval",
@@ -89,7 +88,7 @@ def step_time_and_fraction(r: dict) -> tuple[float, float]:
     ideal compute time on *useful* (model) flops / bound time."""
     rl = r.get("roofline", {})
     bound = max(rl.get("compute_s", 0), rl.get("memory_s", 0), rl.get("collective_s", 0))
-    useful = r.get("model_flops_per_device", 0) / PEAK_FLOPS_BF16
+    useful = r.get("model_flops_per_device", 0) / chip_peaks(MODELED_CHIP).flops_bf16
     frac = useful / bound if bound > 0 else 0.0
     return bound, frac
 

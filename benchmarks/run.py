@@ -20,6 +20,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma list of bench names")
     args = ap.parse_args()
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         fig8_overall,
         fig10_pruning,

@@ -41,6 +41,7 @@ from repro.core.search import SearchParams
 from repro.data.corpus import CorpusConfig, make_corpus
 from repro.obs.export import write_metrics_snapshot
 from repro.obs.metrics import GLOBAL
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.batcher import BatcherConfig, SearchRequest
 from repro.serving.hybrid_service import HybridSearchService, ServiceConfig
 
@@ -454,6 +455,7 @@ def run_compaction(
 
 
 def main() -> None:
+    enable_compile_cache()
     import argparse
 
     ap = argparse.ArgumentParser()

@@ -50,7 +50,13 @@ import numpy as np
 
 from repro.core import knn_graph, pruning
 from repro.core.index import BuildConfig, HybridIndex
-from repro.core.knn_graph import KnnConfig, _merge_topk, new_node_reverse
+from repro.core.knn_graph import (
+    KnnConfig,
+    _chunked,
+    _chunked_tree,
+    _merge_topk,
+    new_node_reverse,
+)
 from repro.core.logical_edges import LogicalEdges, build_logical_edges
 from repro.core.pruning import self_scores
 from repro.core.search import SearchParams, search
@@ -61,7 +67,6 @@ from repro.core.usms import (
     stack_weights,
     weighted_query,
 )
-from repro.kernels import ops
 from repro.runtime import dispatch
 
 # the donated loop program is built lazily at first use: donation is only
@@ -113,23 +118,6 @@ class GraphArrays:
     self_ip: jax.Array  # (N,)
 
 
-def _pad_rows(a: jax.Array, pad: int, fill) -> jax.Array:
-    if pad == 0:
-        return a
-    return jnp.concatenate([a, jnp.full((pad,) + a.shape[1:], fill, a.dtype)])
-
-
-def _chunked(a: jax.Array, chunk: int, fill) -> jax.Array:
-    """Pad rows to a multiple of ``chunk`` and reshape to (n_chunks, chunk, ...)."""
-    pad = (-a.shape[0]) % chunk
-    a = _pad_rows(a, pad, fill)
-    return a.reshape((-1, chunk) + a.shape[1:])
-
-
-def _chunked_tree(t, chunk: int, fill):
-    return jax.tree.map(lambda a: _chunked(a, chunk, fill), t)
-
-
 # ---------------------------------------------------------------------------
 # Stage 1: NN-Descent, fully in-trace
 # ---------------------------------------------------------------------------
@@ -154,13 +142,7 @@ def _descent_init(
         if nbr_ids.shape[1] < k:
             extra = knn_graph._init_graph(n, k - nbr_ids.shape[1], k0)
             nbr_ids = jnp.concatenate([nbr_ids, extra], axis=1)
-    # fused score + full sort (k == row width) — operation-for-operation the
-    # same as knn_graph.build_knn_graph's prologue, so both paths agree bitwise
-    top, pos = ops.fused_topk_vs_ids(
-        queries, corpus, nbr_ids, k, use_kernel=cfg.use_kernel
-    )
-    nbr_ids = ops.take_topk_ids(nbr_ids, pos)
-    scores = jnp.where(nbr_ids >= 0, top, -jnp.inf)
+    nbr_ids, scores = knn_graph.score_sorted_rows(queries, corpus, nbr_ids, cfg)
     return BuildState(nbr_ids=nbr_ids, nbr_scores=scores, key=key)
 
 
@@ -345,7 +327,7 @@ def _prune_all(
 
 
 def _entry_points(
-    corpus: FusedVectors, sip: jax.Array, n_entry: int, use_kernel: bool | None
+    corpus: FusedVectors, sip: jax.Array, n_entry: int
 ) -> jax.Array:
     """Union of top-norm nodes under the fused metric AND each single path,
     so entry quality holds for any query weights."""
@@ -359,9 +341,7 @@ def _entry_points(
         PathWeights.make(0.0, 1.0, 0.0),
         PathWeights.make(0.0, 0.0, 1.0),
     ):
-        qw = weighted_query(corpus, w)
-        cands = jax.tree.map(lambda a: a[:, None], qw)
-        norms = ops.hybrid_scores(qw, cands, use_kernel=use_kernel)[:, 0]
+        norms = self_scores(weighted_query(corpus, w))
         entry_parts.append(jax.lax.top_k(norms, per)[1])
     cat = jnp.concatenate(entry_parts).astype(jnp.int32)
     entries = pruning.unique_take(
@@ -397,12 +377,12 @@ def _build_graph_program(
         path_ids = _path_refinement(corpus, knn_ids, key, cfg, _graph_pk(cfg))
 
     # Steps 2-3: RNG-IP joint pruning + keyword recycling
-    cself = self_scores(corpus, use_kernel=cfg.prune.use_kernel)
+    cself = self_scores(corpus)
     sem, kw = _prune_all(corpus, knn_ids, knn_scores, cself, path_ids, cfg.prune)
 
     # entry points (§4.2.1)
     n_entry = min(cfg.n_entry, corpus.n)
-    entries = _entry_points(corpus, cself, n_entry, cfg.prune.use_kernel)
+    entries = _entry_points(corpus, cself, n_entry)
     return GraphArrays(
         knn_ids=knn_ids,
         knn_scores=knn_scores,
@@ -458,9 +438,9 @@ def _build_graph_host(
         corpus, knn_ids, knn_scores, cfg.prune, path_ids=path_ids
     )
     dispatch.tick()
-    sip = self_scores(corpus, use_kernel=cfg.prune.use_kernel)
+    sip = self_scores(corpus)
     dispatch.tick(3)  # the three per-path top-norm scoring passes below
-    entries = _entry_points(corpus, sip, min(cfg.n_entry, corpus.n), cfg.prune.use_kernel)
+    entries = _entry_points(corpus, sip, min(cfg.n_entry, corpus.n))
     return GraphArrays(
         knn_ids=knn_ids,
         knn_scores=knn_scores,
@@ -547,7 +527,7 @@ def _insert_program(
     )
 
     cself = jnp.concatenate(
-        [old_self_ip, self_scores(new_docs, use_kernel=prune_cfg.use_kernel)]
+        [old_self_ip, self_scores(new_docs)]
     )
     # reverse edges among the new nodes only — merged_ids holds GLOBAL ids,
     # so old-corpus targets must not be mistaken for new-node rows

@@ -48,22 +48,6 @@ SEGMENT_AXES = ("pod", "data")  # axes that shard segments (present subset used)
 QUERY_AXIS = "model"  # axis that shards the query batch
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map moved out of jax.experimental around 0.5, and its
-    replication-check kwarg was renamed check_rep -> check_vma along the way;
-    support every combination (the container pins an older jax than the TPU
-    fleet)."""
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, **kw, check_vma=False)
-        except TypeError:  # public jax.shard_map, pre-rename kwarg
-            return jax.shard_map(f, **kw, check_rep=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, **kw, check_rep=False)
-
-
 @partial(
     jax.tree_util.register_dataclass,
     data_fields=["index", "global_ids"],
@@ -345,11 +329,12 @@ def make_sharded_graph_builder(mesh: Mesh, cfg: BuildConfig):
         self_ip=spec,
     )
     builder = jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_build,
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: spec, _queries_struct()), spec),
             out_specs=graph_specs,
+            check_vma=False,
         )
     )
     _sharded_builder_cache[cache_key] = builder
@@ -548,7 +533,7 @@ def make_distributed_search_padded(
             expanded,
         )
 
-    shard_fn = _shard_map(
+    shard_fn = jax.shard_map(
         local_search,
         mesh=mesh,
         in_specs=(
@@ -562,6 +547,7 @@ def make_distributed_search_padded(
             q_spec,
         ),
         out_specs=(P(), P(), P(), P()),
+        check_vma=False,
     )
 
     @jax.jit
@@ -725,7 +711,7 @@ def make_distributed_descent_round(mesh: Mesh, cfg):
         return ids[None], sc[None]
 
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             local_round,
             mesh=mesh,
             in_specs=(
@@ -735,5 +721,6 @@ def make_distributed_descent_round(mesh: Mesh, cfg):
                 spec,
             ),
             out_specs=(spec, spec),
+            check_vma=False,
         )
     )

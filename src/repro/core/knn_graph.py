@@ -95,6 +95,52 @@ def _descent_round_chunk(
     return _merge_topk(chunk_nbrs, chunk_scores, sel_ids, sel_scores, k)
 
 
+def _pad_rows(a: jax.Array, pad: int, fill) -> jax.Array:
+    if pad == 0:
+        return a
+    return jnp.concatenate([a, jnp.full((pad,) + a.shape[1:], fill, a.dtype)])
+
+
+def _chunked(a: jax.Array, chunk: int, fill) -> jax.Array:
+    """Pad rows to a multiple of ``chunk`` and reshape to (n_chunks, chunk, ...)."""
+    pad = (-a.shape[0]) % chunk
+    a = _pad_rows(a, pad, fill)
+    return a.reshape((-1, chunk) + a.shape[1:])
+
+
+def _chunked_tree(t, chunk: int, fill):
+    return jax.tree.map(lambda a: _chunked(a, chunk, fill), t)
+
+
+def score_sorted_rows(
+    queries: FusedVectors,
+    corpus: FusedVectors,
+    nbr_ids: jax.Array,  # (N, K) neighbor ids, PAD_IDX allowed
+    cfg: KnnConfig,
+) -> tuple[jax.Array, jax.Array]:
+    """Fused score + full sort of every row's neighbor list (k == row width,
+    so the fused top-k IS the sort). Node chunks stream through ``lax.map``
+    like the descent rounds, so the candidate gather is bounded by one
+    chunk, never (N, K) rows at once. Returns (ids, scores) with -inf
+    scores on PAD slots."""
+    n, k = nbr_ids.shape
+    chunk = min(cfg.node_chunk, n)
+
+    def one(x):
+        qs, ids = x
+        top, pos = ops.fused_topk_vs_ids(
+            qs, corpus, ids, k, use_kernel=cfg.use_kernel
+        )
+        ids = ops.take_topk_ids(ids, pos)
+        return ids, jnp.where(ids >= 0, top, -jnp.inf)
+
+    ids, scores = jax.lax.map(
+        one,
+        (_chunked_tree(queries, chunk, 0), _chunked(nbr_ids, chunk, PAD_IDX)),
+    )
+    return ids.reshape(-1, k)[:n], scores.reshape(-1, k)[:n]
+
+
 # jitted wrapper for the legacy host-driven chunk loop; the device-resident
 # pipeline (core/build_pipeline.py) traces the plain body inside lax.map
 _descent_round_chunk_jit = jax.jit(
@@ -137,15 +183,9 @@ def build_knn_graph(
             nbr_ids = jnp.concatenate([nbr_ids, extra], axis=1)
     node_ids = jnp.arange(n, dtype=jnp.int32)
     dispatch.tick()
-    # fused score + full sort of the initial rows (k == row width, so the
-    # fused top-k IS the sort); mirrored operation-for-operation by the
-    # pipeline prologue (build_pipeline._descent_init) so both build paths
-    # stay bitwise-identical
-    top, pos = ops.fused_topk_vs_ids(
-        queries, corpus, nbr_ids, k, use_kernel=cfg.use_kernel
-    )
-    nbr_ids = ops.take_topk_ids(nbr_ids, pos)
-    scores = jnp.where(nbr_ids >= 0, top, -jnp.inf)
+    # the same prologue as the pipeline (build_pipeline._descent_init), so
+    # both build paths stay bitwise-identical
+    nbr_ids, scores = score_sorted_rows(queries, corpus, nbr_ids, cfg)
 
     for it in range(cfg.iters):
         key, kr = jax.random.split(key)

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 
 from repro.core.knn_graph import reverse_neighbors
 from repro.core.usms import PAD_IDX, FusedVectors, PathWeights, weighted_query
-from repro.kernels import ops, ref  # noqa: F401  (ref re-exported for tests)
+from repro.kernels import ops, ref
 from repro.runtime import dispatch
 
 NEG = -1e30
@@ -255,10 +255,15 @@ def _prune_chunk(
 _prune_chunk_jit = jax.jit(_prune_chunk, static_argnames=("cfg",))
 
 
-def self_scores(corpus: FusedVectors, use_kernel: bool | None = None) -> jax.Array:
-    """IP(v, v) — fused self-similarity (squared fused norm)."""
+def self_scores(corpus: FusedVectors) -> jax.Array:
+    """IP(v, v) — fused self-similarity (squared fused norm).
+
+    A per-row reduction, so it runs as jnp on every backend: through the
+    candidate kernel each row would be a one-candidate tile padded to
+    C_TILE lanes — 128x the work, and an (N, C_TILE, Dd) gather that does
+    not fit a chip's memory at corpus scale."""
     cands = jax.tree.map(lambda a: a[:, None], corpus)
-    return ops.hybrid_scores(corpus, cands, use_kernel=use_kernel)[:, 0]
+    return ref.hybrid_scores_ref(corpus, cands)[:, 0]
 
 
 def rng_ip_prune(
@@ -273,7 +278,7 @@ def rng_ip_prune(
     n = corpus.n
     rev = reverse_neighbors(knn_ids, max(cfg.degree // 4, 1))
     dispatch.tick()
-    cself = self_scores(corpus, use_kernel=cfg.use_kernel)
+    cself = self_scores(corpus)
     node_ids = jnp.arange(n, dtype=jnp.int32)
     sems, kws = [], []
     for s in range(0, n, cfg.node_chunk):

@@ -328,7 +328,27 @@ def _search_one(
              ring, kw_ids, kw_scores, n_expanded)
     state = jax.lax.fori_loop(0, p.iters, body, state)
     (pool_ids, pool_scores, _, _, _, _, kw_ids, kw_scores, n_expanded) = state
+    return _final_results(
+        index, q_raw, q_keywords, spec, p,
+        pool_ids, pool_scores, kw_ids, kw_scores, n_expanded,
+    )
 
+
+def _final_results(
+    index: HybridIndex,
+    q_raw: FusedVectors,
+    q_keywords: jax.Array,
+    spec: FusionSpec,
+    p: SearchParams,
+    pool_ids: jax.Array,  # (P,) primary pool
+    pool_scores: jax.Array,  # (P,) traversal scores
+    kw_ids: jax.Array,  # (kw_pool_size,) twin pool
+    kw_scores: jax.Array,
+    n_expanded: jax.Array,
+):
+    """Final results of one query (Algorithm 2 l.29-30) from its candidate
+    pools, however they were filled: a graph traversal or a scan."""
+    n = index.n
     # ---- final results (l.29-30): merge pools, keyword filter, alive filter
     res_ids = jnp.concatenate([pool_ids, kw_ids])
     res_scores = jnp.concatenate([pool_scores, kw_scores])
@@ -386,9 +406,10 @@ def _search_one(
     return out_ids, top, out_ps, n_expanded
 
 
-# incremented once per trace of search_padded (the Python body only runs
-# when jit misses its cache) — the observable the shape-bucketing tests
-# and the CI obs gate assert on: retraces == compiles for this entry point.
+# incremented once per trace of search_padded or of its exact twin
+# scan_padded, the grow segment's read (the Python body only runs when jit
+# misses its cache) — the observable the shape-bucketing tests and the CI
+# obs gate assert on: retraces == compiles for these entry points.
 # Lives in the process-wide metrics registry so benches and the serving
 # exposition read the same series (obs naming convention, DESIGN.md §12).
 _TRACE_COUNTER = _OBS.counter(
@@ -439,6 +460,92 @@ def search_padded(
 
 # retained name for callers of the private batched entry point
 _search_batch = search_padded
+
+
+SCAN_CHUNK = 256  # rows scored per step of a scan: bounds the row gather
+
+
+def _scan_scores(
+    index: HybridIndex, qw: FusedVectors, p: SearchParams
+) -> jax.Array:
+    """(B, n) scores of every row against every weighted query, in row
+    chunks so the gathered rows stay (B, SCAN_CHUNK); dead rows NEG."""
+    n, b = index.n, qw.dense.shape[0]
+    c = min(SCAN_CHUNK, n)
+    ids = jnp.arange(-(-n // c) * c, dtype=jnp.int32)
+    ids = jnp.where(ids < n, ids, PAD_IDX).reshape(-1, c)
+    scores = jax.lax.map(
+        lambda blk: ops.hybrid_scores_vs_ids(
+            qw, index.corpus, jnp.broadcast_to(blk, (b, c)),
+            use_kernel=p.use_kernel,
+        ),
+        ids,
+    )  # (n_chunks, B, c)
+    scores = jnp.moveaxis(scores, 0, 1).reshape(b, -1)[:, :n]
+    return jnp.where(index.alive[None, :], scores, NEG)
+
+
+def _scan_one(
+    index: HybridIndex,
+    scores: jax.Array,  # (n,) this query's score of every row
+    q_raw: FusedVectors,
+    q_keywords: jax.Array,
+    spec: FusionSpec,
+    p: SearchParams,
+):
+    """``_search_one`` with every live row scored instead of a traversal:
+    the pools hold the exact best rows, so the results are exact."""
+    n = index.n
+
+    def best(s, width):
+        width_n = min(width, n)
+        top, pos = jax.lax.top_k(s, width_n)
+        pad = width - width_n
+        top = jnp.concatenate([top, jnp.full((pad,), NEG, top.dtype)])
+        pos = jnp.concatenate(
+            [pos.astype(jnp.int32), jnp.full((pad,), PAD_IDX, jnp.int32)]
+        )
+        return jnp.where(top > NEG, pos, PAD_IDX), top
+
+    pool_ids, pool_scores = best(scores, p.pool_size)
+    kw_ids = jnp.full((p.kw_pool_size,), PAD_IDX, jnp.int32)
+    kw_scores = jnp.full((p.kw_pool_size,), NEG, jnp.float32)
+    if p.use_keywords:
+        match = has_keyword_overlap(index.corpus.lexical.idx, q_keywords[None, :])
+        kw_ids, kw_scores = best(jnp.where(match, scores, NEG), p.kw_pool_size)
+    return _final_results(
+        index, q_raw, q_keywords, spec, p,
+        pool_ids, pool_scores, kw_ids, kw_scores, jnp.int32(n),
+    )
+
+
+@partial(jax.jit, static_argnames=("params",))
+def scan_padded(
+    index: HybridIndex,
+    queries: FusedVectors,
+    fusion: Union[FusionSpec, PathWeights],
+    keywords: jax.Array,  # (B, Kw) required keywords, PAD_IDX padded
+    entities: jax.Array,  # (B, Eq) unused: a scan walks no logical edge
+    params: SearchParams,
+) -> SearchResult:
+    """Exact twin of ``search_padded`` for a small segment: every live row
+    is scored, so a passage is always found by a query equal to its own
+    vector, which a graph traversal does not promise. Same arguments and
+    results; ``expanded`` counts the rows scored. Logical edges are not
+    walked (``params.use_kg`` must be False)."""
+    if params.use_kg:
+        raise ValueError("scan_padded walks no logical edge: use search_padded")
+    del entities
+    _TRACE_COUNTER.inc()
+    if isinstance(fusion, PathWeights):
+        fusion = FusionSpec.from_weights(fusion)
+    spec = broadcast_spec(fusion, queries.dense.shape[0])
+    qw = weighted_query(queries, spec.weights)
+    scores = _scan_scores(index, qw, params)
+    ids, out_scores, ps, expanded = jax.vmap(
+        lambda s, qr, kw, sp: _scan_one(index, s, qr, kw, sp, params)
+    )(scores, queries, keywords, spec)
+    return SearchResult(ids, out_scores, expanded, ps)
 
 
 def search(

@@ -186,52 +186,59 @@ def fused_topk_pallas(
     Slots beyond k — and slots with no live candidate — hold (NEG, PAD_IDX).
     """
     b, dd = qd.shape
-    _, ps = qsi.shape
+    _, ps = qsi.shape  # query nnz caps
     _, pf = qfi.shape
-    c = cd.shape[1]
+    _, cps, c = csi.shape  # candidate nnz caps (may differ from the query's)
+    cpf = cfi.shape[1]
     assert c % c_tile == 0, f"C={c} not a multiple of c_tile={c_tile}"
     kp = k_pad(k)
     grid = (b, c // c_tile)
 
-    q_row = lambda i, j: (i, 0)
+    # per-row operands as (B, 1, X) with a squeezed leading block dim — the
+    # (1, X) block then equals the array's last two dims, which Mosaic
+    # accepts for any B (see hybrid_distance_pallas)
+    q_row = lambda i, j: (i, 0, 0)
     cand3 = lambda i, j: (i, 0, j)
     dense3 = lambda i, j: (i, j, 0)
-    crow = lambda i, j: (i, j)
+    crow = lambda i, j: (i, 0, j)
+    rows = lambda a: a.reshape(a.shape[0], 1, a.shape[-1])
 
     in_specs = [
-        pl.BlockSpec((1, dd), q_row),
-        pl.BlockSpec((1, ps), q_row),
-        pl.BlockSpec((1, ps), q_row),
-        pl.BlockSpec((1, pf), q_row),
-        pl.BlockSpec((1, pf), q_row),
+        pl.BlockSpec((None, 1, dd), q_row),
+        pl.BlockSpec((None, 1, ps), q_row),
+        pl.BlockSpec((None, 1, ps), q_row),
+        pl.BlockSpec((None, 1, pf), q_row),
+        pl.BlockSpec((None, 1, pf), q_row),
         pl.BlockSpec((1, c_tile, dd), dense3),
-        pl.BlockSpec((1, ps, c_tile), cand3),
-        pl.BlockSpec((1, ps, c_tile), cand3),
-        pl.BlockSpec((1, pf, c_tile), cand3),
-        pl.BlockSpec((1, pf, c_tile), cand3),
-        pl.BlockSpec((1, c_tile), crow),
+        pl.BlockSpec((1, cps, c_tile), cand3),
+        pl.BlockSpec((1, cps, c_tile), cand3),
+        pl.BlockSpec((1, cpf, c_tile), cand3),
+        pl.BlockSpec((1, cpf, c_tile), cand3),
+        pl.BlockSpec((None, 1, c_tile), crow),
     ]
-    args = [qd, qsi, qsv, qfi, qfv, cd, csi, csv, cfi, cfv, cid]
+    args = [rows(qd), rows(qsi), rows(qsv), rows(qfi), rows(qfv),
+            cd, csi, csv, cfi, cfv, rows(cid)]
     if bias is not None:
-        in_specs.append(pl.BlockSpec((1, c_tile), crow))
-        args.append(bias)
+        in_specs.append(pl.BlockSpec((None, 1, c_tile), crow))
+        args.append(rows(bias))
     if cscale is not None:
-        in_specs.append(pl.BlockSpec((1, c_tile), crow))
-        args.append(cscale)
+        in_specs.append(pl.BlockSpec((None, 1, c_tile), crow))
+        args.append(rows(cscale))
 
-    return pl.pallas_call(
+    out_s, out_i = pl.pallas_call(
         _make_fused_topk_kernel(k, c_tile, bias is not None,
                                 cscale is not None),
         grid=grid,
         in_specs=in_specs,
         # both outputs pinned per grid row -> VMEM-resident accumulators
         out_specs=[
-            pl.BlockSpec((1, kp), q_row),
-            pl.BlockSpec((1, kp), q_row),
+            pl.BlockSpec((None, 1, kp), q_row),
+            pl.BlockSpec((None, 1, kp), q_row),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, kp), jnp.float32),
-            jax.ShapeDtypeStruct((b, kp), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, kp), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, kp), jnp.int32),
         ],
         interpret=interpret,
     )(*args)
+    return out_s.reshape(b, kp), out_i.reshape(b, kp)

@@ -116,42 +116,50 @@ def hybrid_distance_pallas(
     Returns (B, C) float32 hybrid scores (higher = more similar).
     """
     b, dd = qd.shape
-    _, ps = qsi.shape
+    _, ps = qsi.shape  # query nnz caps
     _, pf = qfi.shape
-    c = cd.shape[1]
+    _, cps, c = csi.shape  # candidate nnz caps (may differ from the query's)
+    cpf = cfi.shape[1]
     assert c % c_tile == 0, f"C={c} not a multiple of c_tile={c_tile}"
     grid = (b, c // c_tile)
 
-    # Query blocks are pinned per grid row (index_map ignores the candidate
-    # tile coordinate) -> VMEM-resident across candidate tiles.
-    q_row = lambda i, j: (i, 0)
+    # Per-row operands travel as (B, 1, X) with a squeezed leading block
+    # dim: the kernel sees (1, X) blocks whose last two dims equal the
+    # array's, which Mosaic accepts for any B (a (1, X) block of a (B, X)
+    # array is refused for B > 1). Query blocks are pinned per grid row
+    # (index_map ignores the candidate tile coordinate) -> VMEM-resident
+    # across candidate tiles.
+    q_row = lambda i, j: (i, 0, 0)
     cand3 = lambda i, j: (i, 0, j)  # (1, P, C_TILE) tiles along last dim
     dense3 = lambda i, j: (i, j, 0)  # (1, C_TILE, Dd) tiles along middle dim
-    crow = lambda i, j: (i, j)
+    crow = lambda i, j: (i, 0, j)
+    rows = lambda a: a.reshape(a.shape[0], 1, a.shape[-1])
 
     has_scale = cscale is not None
     in_specs = [
-        pl.BlockSpec((1, dd), q_row),
-        pl.BlockSpec((1, ps), q_row),
-        pl.BlockSpec((1, ps), q_row),
-        pl.BlockSpec((1, pf), q_row),
-        pl.BlockSpec((1, pf), q_row),
+        pl.BlockSpec((None, 1, dd), q_row),
+        pl.BlockSpec((None, 1, ps), q_row),
+        pl.BlockSpec((None, 1, ps), q_row),
+        pl.BlockSpec((None, 1, pf), q_row),
+        pl.BlockSpec((None, 1, pf), q_row),
         pl.BlockSpec((1, c_tile, dd), dense3),
-        pl.BlockSpec((1, ps, c_tile), cand3),
-        pl.BlockSpec((1, ps, c_tile), cand3),
-        pl.BlockSpec((1, pf, c_tile), cand3),
-        pl.BlockSpec((1, pf, c_tile), cand3),
+        pl.BlockSpec((1, cps, c_tile), cand3),
+        pl.BlockSpec((1, cps, c_tile), cand3),
+        pl.BlockSpec((1, cpf, c_tile), cand3),
+        pl.BlockSpec((1, cpf, c_tile), cand3),
     ]
-    operands = [qd, qsi, qsv, qfi, qfv, cd, csi, csv, cfi, cfv]
+    operands = [rows(qd), rows(qsi), rows(qsv), rows(qfi), rows(qfv),
+                cd, csi, csv, cfi, cfv]
     if has_scale:
-        in_specs.append(pl.BlockSpec((1, c_tile), crow))
-        operands.append(cscale)
+        in_specs.append(pl.BlockSpec((None, 1, c_tile), crow))
+        operands.append(rows(cscale))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _make_hybrid_distance_kernel(has_scale),
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, c_tile), crow),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, c_tile), crow),
+        out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
         interpret=interpret,
     )(*operands)
+    return out.reshape(b, c)

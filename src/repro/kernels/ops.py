@@ -92,6 +92,21 @@ def _pad_candidates_q(
     )
 
 
+def _nnz_major(cands) -> tuple[jax.Array, ...]:
+    """(B, C, P) ELL leaves -> the kernel's nnz-major (B, P, C) layout (see
+    hybrid_distance.py). Values leave the transpose as f32: Mosaic refuses
+    fp16 vector loads (quantized storage keeps fp16 ELL values), and the
+    kernels upcast every value to f32 before use, so the scores are the
+    same bits either way."""
+    t = lambda a: jnp.swapaxes(a, 1, 2)
+    return (
+        t(cands.learned.idx),
+        t(cands.learned.val).astype(jnp.float32),
+        t(cands.lexical.idx),
+        t(cands.lexical.val).astype(jnp.float32),
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("c_tile", "use_kernel", "interpret"))
 def hybrid_scores(
     q: FusedVectors,
@@ -120,11 +135,7 @@ def hybrid_scores(
     else:
         cands, c_orig = _pad_candidates(cands, c_tile)
         cd, cscale = cands.dense, None
-    # nnz-major candidate layout for the kernel (see hybrid_distance.py).
-    csi = jnp.swapaxes(cands.learned.idx, 1, 2)
-    csv = jnp.swapaxes(cands.learned.val, 1, 2)
-    cfi = jnp.swapaxes(cands.lexical.idx, 1, 2)
-    cfv = jnp.swapaxes(cands.lexical.val, 1, 2)
+    csi, csv, cfi, cfv = _nnz_major(cands)
     out = hybrid_distance_pallas(
         q.dense,
         q.learned.idx,
@@ -203,10 +214,7 @@ def fused_topk(
         cid = jnp.pad(cid, grow, constant_values=PAD_IDX)
         if bias is not None:
             bias = jnp.pad(bias, grow)
-    csi = jnp.swapaxes(cands.learned.idx, 1, 2)
-    csv = jnp.swapaxes(cands.learned.val, 1, 2)
-    cfi = jnp.swapaxes(cands.lexical.idx, 1, 2)
-    cfv = jnp.swapaxes(cands.lexical.val, 1, 2)
+    csi, csv, cfi, cfv = _nnz_major(cands)
     out_s, out_i = fused_topk_pallas(
         q.dense,
         q.learned.idx,

@@ -26,7 +26,12 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, list_archs
-from repro.launch.hlo_analysis import analyze_hlo, roofline_terms
+from repro.launch.hlo_analysis import (
+    MODELED_CHIP,
+    analyze_hlo,
+    chip_peaks,
+    roofline_terms,
+)
 from repro.launch.mesh import make_production_mesh, mesh_dp_size, mesh_model_size
 from repro.models import transformer as tfm
 from repro.models.config import SHAPES, ModelConfig
@@ -334,6 +339,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, overrides: str | None 
         hlo_bytes=hlo["hbm_bytes"],
         coll_bytes_per_device=hlo["collective_bytes"],
         n_chips=record["n_devices"],
+        peaks=chip_peaks(MODELED_CHIP),
     )
     print("roofline:", {k: (round(v, 4) if isinstance(v, float) else v)
                         for k, v in record["roofline"].items()})

@@ -271,12 +271,46 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# roofline terms (TPU v5e constants from the assignment)
+# roofline terms: published per-chip peaks, one row per device kind
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s of one chip-to-chip interconnect link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``. A kind that is not here is an error,
+# never a default: a roofline priced with another chip's peaks is wrong.
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12,
+        hbm_bw=819e9,
+        # 1,600 Gbit/s per chip over its 4 links: 50 GB/s per link, the
+        # unit the collective term prices
+        ici_bw=1600e9 / 8 / 4,
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+# the device kind the dry runs and the kernel and roofline benches price at
+MODELED_CHIP = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; raises for an unknown kind."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(CHIP_PEAKS)}"
+        ) from None
 
 
 def roofline_terms(
@@ -285,13 +319,14 @@ def roofline_terms(
     hlo_bytes: float,
     coll_bytes_per_device: float,
     n_chips: int,
+    peaks: ChipPeaks,
 ) -> dict:
     """Three-term roofline over PER-DEVICE quantities (the SPMD-partitioned
     module is the per-device program, so chips appear via the partitioned
-    shapes, not an extra division)."""
-    compute_s = hlo_flops / PEAK_FLOPS_BF16
-    memory_s = hlo_bytes / HBM_BW
-    collective_s = coll_bytes_per_device / ICI_BW
+    shapes, not an extra division), priced at ``peaks``."""
+    compute_s = hlo_flops / peaks.flops_bf16
+    memory_s = hlo_bytes / peaks.hbm_bw
+    collective_s = coll_bytes_per_device / peaks.ici_bw
     terms = {
         "compute_s": compute_s,
         "memory_s": memory_s,

@@ -22,11 +22,14 @@ claims (§5) presume but the one-shot ``search()`` API does not provide:
   * a segmented snapshot may carry a *grow segment* (a small mutable
     ``HybridIndex`` absorbing streaming inserts, managed by
     ``serving.segment_router.SegmentRouter``): reads fan out to the sealed
-    executable AND a ``search_padded`` pass over the grow segment, then
-    merge per-row top-k in global-id space. The grow pass deliberately uses
-    ``search_padded``'s own jit cache, NOT the AOT ``executable_cache``, so
-    sealed-segment executables survive every insert (the grow segment
-    changes shape per insert; the sealed one does not);
+    executable AND an exact ``scan_padded`` pass over the grow segment (a
+    graph search could miss a fresh passage even by its own vector; with
+    KG params the grow segment is traversed by ``search_padded``, whose
+    logical reward is defined by hops), then merge per-row top-k in
+    global-id space. The grow pass deliberately uses its entry point's own
+    jit cache, NOT the AOT ``executable_cache``, so sealed-segment
+    executables survive every insert (the grow segment changes shape per
+    insert; the sealed one does not);
   * token-bucket admission control (``BatcherConfig``-level queue bound is
     backpressure; ``AdmissionConfig`` buckets are rate policy) runs in front
     of ``MicroBatcher.enqueue``, with per-tenant quotas keyed on
@@ -75,6 +78,7 @@ from repro.core.search import (
     SearchParams,
     SearchResult,
     resolve_params,
+    scan_padded,
     search_padded,
     search_padded_trace_count,
 )
@@ -617,7 +621,8 @@ class HybridSearchService:
 
     @property
     def executable_cache(self) -> dict:
-        """(index/group key, Bucket, SearchParams) -> AOT executable."""
+        """(index/group key, Bucket, SearchParams, query widths) -> AOT
+        executable."""
         return self._exec_cache
 
     def _compile_cached(self, key: tuple, lower):
@@ -647,8 +652,18 @@ class HybridSearchService:
         self.stats._compiles.inc()
         return exe, False
 
+    @staticmethod
+    def _query_widths(args) -> tuple:
+        """The assembled query batch's nnz caps: part of every cache key,
+        since the bucket fixes batch and keyword/entity widths but not the
+        query's (a 128-nnz read-back query must not hit a 32-nnz executable)."""
+        return tuple(a.shape[1:] for a in jax.tree.leaves(args[0]))
+
     def _get_executable(self, snap: _Snapshot, bucket: Bucket, args):
-        key = (self._index_key(snap.index), bucket, self.params)
+        key = (
+            self._index_key(snap.index), bucket, self.params,
+            self._query_widths(args),
+        )
         if self._segmented:
             lower = lambda: self._dist_fn.lower(snap.index, *args)
         else:
@@ -667,7 +682,10 @@ class HybridSearchService:
 
     def _get_group_executable(self, group: SegmentedIndex, bucket: Bucket, args):
         """(executable, cache_hit) for one pool shape group."""
-        key = (group_shape_key(group), bucket, self.params)
+        key = (
+            group_shape_key(group), bucket, self.params,
+            self._query_widths(args),
+        )
         fn = self._group_runner(group)
         return self._compile_cached(key, lambda: fn.lower(group, *args))
 
@@ -801,14 +819,17 @@ class HybridSearchService:
         """Phase two of a segmented read: search the grow segment and merge
         per-row top-k with the sealed results in global-id space.
 
-        The grow pass goes through ``search_padded`` directly — its jit cache
-        retraces as the grow segment changes shape, while the AOT
-        ``executable_cache`` (sealed segments) is never touched. Tombstones
-        need no extra filtering here: both phases already filter on their
-        own ``alive`` masks."""
+        The grow pass scores every live grow row (``scan_padded``), so a
+        passage is found as soon as its insert returns; KG params traverse
+        it with ``search_padded`` instead. Either jit cache retraces as the
+        grow segment changes shape, while the AOT ``executable_cache``
+        (sealed segments) is never touched. Tombstones need no extra
+        filtering here: both phases already filter on their own ``alive``
+        masks."""
         t0 = time.perf_counter()
         traces0 = search_padded_trace_count()
-        gres = search_padded(snap.grow, *args, self.params)
+        grow_pass = search_padded if self.params.use_kg else scan_padded
+        gres = grow_pass(snap.grow, *args, self.params)
         g_local = np.asarray(gres.ids)
         gids_map = np.asarray(snap.grow_gids)
         g_ids = np.where(

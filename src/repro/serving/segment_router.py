@@ -14,10 +14,12 @@ module closes that gap with the classic vector-DB grow-segment scheme
     pipelined per-segment insert program). Sealed segments are never
     touched, so their compiled executables stay warm; the read path merges
     sealed + grow per-row top-k in global-id space
-    (``HybridSearchService._merge_grow``). The published grow segment is
-    padded to power-of-two capacity by default (``RouterConfig.grow_pow2``)
-    so the read path's ``search_padded`` retraces O(log growth) times
-    between compactions instead of once per insert batch;
+    (``HybridSearchService._merge_grow``), reading the grow segment by an
+    exact scan (``core.search.scan_padded``), as Milvus reads a growing
+    segment by brute force. The published grow segment is padded to
+    power-of-two capacity by default (``RouterConfig.grow_pow2``) so the
+    read path's grow pass retraces O(log growth) times between compactions
+    instead of once per insert batch;
   * **sealed** — the immutable stacked segments served through
     ``make_distributed_search_padded``'s cached executable. Deletions
     resolve global ids to (segment, local row) tombstones
@@ -116,7 +118,7 @@ class RouterConfig:
     # giving the router the triplets permanently drops the entity paths
     allow_kg_loss_on_compact: bool = False
     # shape-bucket the PUBLISHED grow segment: pad its capacity to the next
-    # power of two so the read path's search_padded retraces O(log growth)
+    # power of two so the read path's grow pass retraces O(log growth)
     # times between compactions instead of once per insert batch (pad rows
     # are dead — alive=False, PAD edges — and unreachable: no entry point or
     # edge ever references them)

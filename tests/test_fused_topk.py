@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.search import SearchParams, resolve_params
-from repro.core.usms import PAD_IDX
+from repro.core.usms import PAD_IDX, quantize_corpus
 from repro.kernels import ops, ref
 from repro.kernels.fused_topk import K_LANE, NEG, k_pad
 from tests.helpers import random_fused
@@ -87,6 +87,28 @@ def test_kernel_matches_oracle(b, c, k, pad_frac, with_bias, c_tile):
     )
     want = ref.fused_topk_ref(q, cands, cid, bias, k)
     assert_topk_match(got, want)
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("op", ["hybrid_scores", "fused_topk"])
+def test_kernel_matches_oracle_with_wider_candidates(op, quantized):
+    """Queries with fewer nnz slots than the candidates (the serving shape:
+    32/16-nnz queries against 128/64-nnz passages): every candidate slot
+    must reach the kernel, not just the first query-width ones."""
+    rng = np.random.default_rng(17)
+    q = random_fused(rng, (3,), d_dense=16, ps=3, pf=2, vs=13, vf=7)
+    cands = random_fused(rng, (3, 40), d_dense=16, ps=9, pf=5, vs=13, vf=7)
+    if quantized:
+        cands = quantize_corpus(cands)
+    if op == "hybrid_scores":
+        got = ops.hybrid_scores(q, cands, c_tile=8, use_kernel=True, interpret=True)
+        want = ops.hybrid_scores(q, cands, use_kernel=False)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+    else:
+        cid = jnp.arange(120, dtype=jnp.int32).reshape(3, 40)
+        got = ops.fused_topk(q, cands, cid, 8, c_tile=8, use_kernel=True, interpret=True)
+        want = ops.fused_topk(q, cands, cid, 8, use_kernel=False)
+        assert_topk_match(got, want)
 
 
 def test_oracle_matches_raw_lax_topk():

@@ -10,8 +10,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import BuildConfig, KnnConfig, PruneConfig, build_index, insert, mark_deleted
-from repro.core.search import SearchParams, search
-from repro.core.usms import PathWeights, weighted_query
+from repro.core.fusion import FusionSpec
+from repro.core.search import SearchParams, scan_padded, search
+from repro.core.usms import PathWeights, has_keyword_overlap, weighted_query
 from repro.data.corpus import CorpusConfig, make_corpus, ndcg_at_k, recall_at_k
 from repro.kernels import ops
 
@@ -187,3 +188,49 @@ def test_insert_preserves_quality(built):
     # new docs are actually reachable
     new_hit = (np.asarray(res.ids) >= n_keep).any()
     assert new_hit
+
+
+@pytest.mark.parametrize("use_keywords", [False, True])
+def test_scan_padded_is_exact(built, use_keywords):
+    """The grow segment's read scores every live row: its top-k equals the
+    brute-force top-k under the keyword rule, deleted rows never return,
+    and every passage is found first by a query equal to its own vector."""
+    corpus, index, _ = built
+    dead = jnp.arange(0, 1024, 7)
+    index = mark_deleted(index, dead)
+    params = SearchParams(k=10, pool_size=64, use_keywords=use_keywords)
+    spec = FusionSpec.three_path()
+    q = corpus.queries
+    kw = jnp.asarray(corpus.query_keywords)
+    if not use_keywords:
+        kw = jnp.full((q.n, 1), -1, jnp.int32)
+    en = jnp.full((q.n, 1), -1, jnp.int32)
+    got = np.asarray(scan_padded(index, q, spec, kw, en, params).ids)
+
+    scores = np.array(ops.pairwise_scores_chunked(q, corpus.docs))
+    scores[:, np.asarray(dead)] = -np.inf
+    if use_keywords:
+        ok = np.asarray(has_keyword_overlap(corpus.docs.lexical.idx[None], kw[:, None]))
+        has_req = np.asarray((kw >= 0).any(1))
+        scores = np.where(ok | ~has_req[:, None], scores, -np.inf)
+    want = np.argsort(-scores, axis=1, kind="stable")[:, :10]
+    want = np.where(np.take_along_axis(scores, want, 1) > -np.inf, want, -1)
+    for g, w in zip(got, want):
+        assert set(g.tolist()) == set(w.tolist())
+
+    own = corpus.docs[slice(1, 1024, 7)]  # live rows
+    res = scan_padded(
+        index, own, spec, jnp.full((own.n, 1), -1, jnp.int32),
+        jnp.full((own.n, 1), -1, jnp.int32), params,
+    )
+    np.testing.assert_array_equal(np.asarray(res.ids)[:, 0], np.arange(1, 1024, 7))
+
+
+def test_scan_padded_refuses_kg_params(built):
+    corpus, index, _ = built
+    pad = jnp.full((2, 1), -1, jnp.int32)
+    with pytest.raises(ValueError, match="logical edge"):
+        scan_padded(
+            index, corpus.queries[:2], FusionSpec.three_path(), pad, pad,
+            SearchParams(use_kg=True),
+        )

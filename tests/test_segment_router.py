@@ -293,9 +293,10 @@ def test_kg_survives_insert_and_compaction():
 
 def test_grow_pow2_bucketing_limits_retraces(corpus, sealed):
     """Shape-bucketed grow segment: publishing the grow segment padded to
-    power-of-two capacity means the read path's ``search_padded`` retraces
-    once per CAPACITY (O(log growth)) between compactions, not once per
-    insert batch — and dead pad rows never surface in results."""
+    power-of-two capacity means the read path's grow pass (``scan_padded``,
+    counted with ``search_padded``) retraces once per CAPACITY (O(log
+    growth)) between compactions, not once per insert batch — and dead pad
+    rows never surface in results."""
     svc = _service(sealed)
     router = SegmentRouter(svc, BUILD_CFG, RouterConfig(seal_threshold=10**9))
     svc.search(corpus.queries[:4], W, k=5)  # warm the sealed executable
