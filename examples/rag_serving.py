@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
 from repro.core import BuildConfig, KnnConfig, PruneConfig, build_index
-from repro.obs.export import write_chrome_trace
 from repro.core.search import SearchParams
 from repro.data.corpus import CorpusConfig, make_corpus, recall_at_k
 from repro.models import transformer as tfm
@@ -67,9 +66,12 @@ def main():
     prompts = jnp.asarray(rng.integers(0, cfg.vocab, size=(n_requests, 8)), jnp.int32)
     t0 = time.perf_counter()
     # every span of the request — admission, queue wait, batch phases,
-    # context assembly, generation — lands in one trace context
-    with service.tracer.trace("rag_answer", requests=n_requests) as ctx:
-        out, res = rag.answer(corpus.queries, prompts, n_tokens=24, trace=ctx)
+    # context assembly, generation — lands in one trace context; the
+    # profiler records the service's batch phases as serving.<phase> host
+    # spans beside the device operations, on one clock
+    with jax.profiler.trace("results/rag_trace"):
+        with service.tracer.trace("rag_answer", requests=n_requests) as ctx:
+            out, res = rag.answer(corpus.queries, prompts, n_tokens=24, trace=ctx)
     dt = time.perf_counter() - t0
 
     rec = recall_at_k(np.asarray(res.ids), corpus.query_relevant[:, :1])
@@ -81,13 +83,12 @@ def main():
           f"{service.stats.compiles} compiled executables, "
           f"{service.stats.requests} requests")
 
-    # observability artifacts: a perfetto-loadable span tree of the request
-    # (chrome://tracing or https://ui.perfetto.dev) and the Prometheus view
-    write_chrome_trace("results/rag_trace.json", service.tracer)
+    # observability artifacts: the request's span tree, the profiler trace
+    # (TensorBoard's profile plugin or https://ui.perfetto.dev) and the
+    # Prometheus view
     spans = sorted({s.name for s in ctx.spans()})
     print(f"trace: {len(ctx.spans())} spans ({', '.join(spans)})")
-    print("trace written to results/rag_trace.json — "
-          "open it in https://ui.perfetto.dev")
+    print("profiler trace written under results/rag_trace/")
     print("metrics exposition (excerpt):")
     for line in service.metrics.render().splitlines():
         if line.startswith("allanpoe_serving_requests_total"):

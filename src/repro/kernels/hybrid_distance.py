@@ -161,5 +161,6 @@ def hybrid_distance_pallas(
         out_specs=pl.BlockSpec((None, 1, c_tile), crow),
         out_shape=jax.ShapeDtypeStruct((b, 1, c), jnp.float32),
         interpret=interpret,
+        name="hybrid_distance",
     )(*operands)
     return out.reshape(b, c)

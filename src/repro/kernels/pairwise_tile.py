@@ -114,4 +114,5 @@ def pairwise_tile_pallas(
         out_specs=pl.BlockSpec((1, k, k), cell),
         out_shape=jax.ShapeDtypeStruct((c, k, k), jnp.float32),
         interpret=interpret,
+        name="pairwise_tile",
     )(d, si, sv, fi, fv, tsi, tsv, tfi, tfv)

@@ -1,9 +1,10 @@
-"""Observability for the serving stack: per-query span trees (``tracer``),
-a lock-protected metrics registry with streaming histograms (``metrics``),
-and Prometheus/JSON/Chrome-trace exposition (``export``). Dependency-free
-by design (stdlib only) — it imports nothing from the rest of ``repro``,
-so every layer (core, runtime, serving, benches) can instrument itself
-without cycles. Naming and span taxonomy: DESIGN.md §12.
+"""Observability for the serving stack: per-query span trees and batch
+phases on the profiler's clock (``tracer``), a lock-protected metrics
+registry with streaming histograms (``metrics``), and Prometheus/JSON
+exposition (``export``). It imports nothing from the rest of ``repro``, so
+every layer (core, runtime, serving, benches) can instrument itself without
+cycles; JAX's profiler is its one import outside the standard library, and
+``tracer`` guards it. Naming and span taxonomy: DESIGN.md §12.
 """
 
 from repro.obs.metrics import (
@@ -17,12 +18,8 @@ from repro.obs.metrics import (
     merged_snapshot,
     time_buckets,
 )
-from repro.obs.tracer import Span, TraceContext, Tracer
-from repro.obs.export import (
-    chrome_trace,
-    write_chrome_trace,
-    write_metrics_snapshot,
-)
+from repro.obs.tracer import Span, TraceContext, Tracer, phase
+from repro.obs.export import write_metrics_snapshot
 
 __all__ = [
     "DEFAULT_TIME_BUCKETS",
@@ -37,7 +34,6 @@ __all__ = [
     "Span",
     "TraceContext",
     "Tracer",
-    "chrome_trace",
-    "write_chrome_trace",
+    "phase",
     "write_metrics_snapshot",
 ]

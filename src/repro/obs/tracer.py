@@ -1,23 +1,28 @@
-"""Per-query span trees for the serving stack.
+"""Per-query span trees and batch phases for the serving stack.
 
 A ``TraceContext`` rides on ``SearchRequest.trace`` through the whole
 request path — admission, queue wait, batch assembly, executable lookup
-(hit/miss/retrace), device dispatch, grow-segment merge, per-replica
-scatter fan-out, fusion re-score — and each stage appends a ``Span``.
-Stages usually record retrospectively (``add_span(name, t0, t1)``) with
-timestamps they measured anyway: a batch phase is timed ONCE and attributed
-to every query in the batch, instead of each query carrying live span
-objects across the pump/submit thread boundary. ``span()`` is the live
-context-manager form for single-owner phases.
+(hit/miss/retrace), device dispatch and read-back, grow-segment merge,
+per-replica scatter fan-out, fusion re-score, delivery — and each stage
+appends a ``Span``. Stages usually record retrospectively
+(``add_span(name, t0, t1)``) with timestamps they measured anyway: a batch
+phase is timed ONCE and attributed to every query in the batch, instead of
+each query carrying live span objects across the pump/submit thread
+boundary. ``span()`` is the live context-manager form for single-owner
+phases.
+
+``phase`` times one batch phase: it records the interval for those
+per-request trees, observes it in a per-phase histogram, and holds a
+``jax.profiler.TraceAnnotation`` named ``serving.<phase>`` open for the
+same interval. The annotation is a host event in the profiler's own trace,
+so a batch phase sits on the device trace's clock, beside the device
+operations it waited for or enqueued.
 
 Timestamps are ``time.perf_counter()`` seconds (monotonic, sub-µs), so a
-span tree is internally ordered but not wall-clock anchored; the Chrome
-trace export (``obs.export``) rebases onto the tracer epoch.
-
-``Tracer`` is the factory plus a bounded ring of finished traces —
-``export_chrome`` turns them into a perfetto-loadable trace-event JSON.
-Everything is lock-protected: spans are appended from submitter, pump, and
-scatter-pool threads concurrently.
+span tree is internally ordered but not wall-clock anchored. ``Tracer`` is
+the factory plus a bounded ring of finished traces. Everything is
+lock-protected: spans are appended from submitter, pump, and scatter-pool
+threads concurrently.
 """
 
 from __future__ import annotations
@@ -26,6 +31,11 @@ import threading
 import time
 from collections import deque
 from typing import Iterator, Optional
+
+try:  # the profiler's host annotations; obs itself needs no JAX
+    from jax.profiler import TraceAnnotation
+except ImportError:  # pragma: no cover - JAX is a dependency of the package
+    TraceAnnotation = None
 
 
 class Span:
@@ -162,7 +172,6 @@ class Tracer:
     most recent ``keep`` trees."""
 
     def __init__(self, keep: int = 256):
-        self.epoch = time.perf_counter()  # chrome-export time zero
         self._lock = threading.Lock()
         self._finished: deque[TraceContext] = deque(maxlen=keep)
 
@@ -178,11 +187,41 @@ class Tracer:
         with self._lock:
             return list(self._finished)
 
-    def export_chrome(self, path=None) -> dict:
-        """Chrome trace-event JSON over every finished trace; see
-        ``obs.export.chrome_trace``."""
-        from repro.obs.export import chrome_trace, write_chrome_trace
 
-        if path is not None:
-            return write_chrome_trace(path, self)
-        return chrome_trace(self.finished, epoch=self.epoch)
+class phase:
+    """Context manager timing one batch phase ``name``; yields the phase's
+    attribute dict, which the body may extend.
+
+    On exit the interval is observed in ``hist`` under the label
+    ``phase=name`` and, when ``phases`` is a list, appended to it as
+    ``(name, t0, t1, attrs)`` for attribution to the batch's requests. For
+    the same interval a ``jax.profiler.TraceAnnotation("serving.<name>")``
+    is open: without a running profiler that costs well under a
+    microsecond. Phases of one batch follow each other; batches on other
+    threads overlap them."""
+
+    __slots__ = ("name", "hist", "phases", "attrs", "t0", "_ann")
+
+    def __init__(self, name: str, hist, phases: Optional[list] = None, **attrs):
+        self.name = name
+        self.hist = hist
+        self.phases = phases
+        self.attrs = attrs
+
+    def __enter__(self) -> dict:
+        self._ann = (
+            TraceAnnotation(f"serving.{self.name}")
+            if TraceAnnotation is not None else None
+        )
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self.attrs
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.hist.observe(t1 - self.t0, phase=self.name)
+        if self.phases is not None:
+            self.phases.append((self.name, self.t0, t1, self.attrs))

@@ -73,7 +73,7 @@ from repro.core.fusion import (
 from repro.obs.export import write_metrics_snapshot
 from repro.obs.metrics import GLOBAL as GLOBAL_METRICS
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.tracer import TraceContext, Tracer
+from repro.obs.tracer import TraceContext, Tracer, phase
 from repro.core.search import (
     SearchParams,
     SearchResult,
@@ -293,10 +293,14 @@ class HybridSearchService:
             "allanpoe_serving_request_latency_seconds",
             "enqueue -> result delivery per request (the bench p50/p99 source)",
         )
-        self._m_batch_exec = self.metrics.histogram(
-            "allanpoe_serving_batch_exec_seconds",
-            "assemble -> deliver per batch",
-            labels=("bucket",),
+        self._m_phase = self.metrics.histogram(
+            "allanpoe_serving_batch_phase_seconds",
+            "time per batch phase (the serving.<phase> profiler spans)",
+            labels=("phase",),
+        )
+        self._m_nodes_expanded = self.metrics.counter(
+            "allanpoe_serving_nodes_expanded_total",
+            "graph nodes the traversal expanded, over real (unpadded) rows",
         )
         self._snap = _Snapshot(index, version=0)
         self._index_bytes_keys: set = set()
@@ -625,10 +629,11 @@ class HybridSearchService:
         executable."""
         return self._exec_cache
 
-    def _compile_cached(self, key: tuple, lower):
-        """(executable, cache_hit) for a cache key, compiling on miss. Every
-        lookup lands in the ``executable_cache_total{outcome}`` counter — the
-        hit-rate series the CI obs gate tracks."""
+    def _compile_cached(self, key: tuple, lower, phases):
+        """(executable, cache_hit) for a cache key, compiling on miss (the
+        ``compile`` phase). Every lookup lands in the
+        ``executable_cache_total{outcome}`` counter — the hit-rate series
+        the CI obs gate tracks."""
         with self._cache_lock:
             exe = self._exec_cache.get(key)
         if exe is not None:
@@ -637,7 +642,8 @@ class HybridSearchService:
         self._m_exec_cache.inc(outcome="miss")
         # compile outside the lock: a cold bucket must not stall warm-bucket
         # batches or snapshot publishes behind a multi-second XLA compile
-        exe = lower().compile()
+        with phase("compile", self._m_phase, phases):
+            exe = lower().compile()
         with self._cache_lock:
             winner = self._exec_cache.get(key)
             if winner is not None:
@@ -659,7 +665,7 @@ class HybridSearchService:
         query's (a 128-nnz read-back query must not hit a 32-nnz executable)."""
         return tuple(a.shape[1:] for a in jax.tree.leaves(args[0]))
 
-    def _get_executable(self, snap: _Snapshot, bucket: Bucket, args):
+    def _get_executable(self, snap: _Snapshot, bucket: Bucket, args, phases):
         key = (
             self._index_key(snap.index), bucket, self.params,
             self._query_widths(args),
@@ -668,7 +674,7 @@ class HybridSearchService:
             lower = lambda: self._dist_fn.lower(snap.index, *args)
         else:
             lower = lambda: search_padded.lower(snap.index, *args, self.params)
-        return self._compile_cached(key, lower)
+        return self._compile_cached(key, lower, phases)
 
     def _group_runner(self, group: SegmentedIndex):
         """Pick the executable factory for one pool group per the placement
@@ -680,14 +686,14 @@ class HybridSearchService:
                 return self._dist_fn
         return self._local_fn
 
-    def _get_group_executable(self, group: SegmentedIndex, bucket: Bucket, args):
+    def _get_group_executable(self, group: SegmentedIndex, bucket: Bucket, args, phases):
         """(executable, cache_hit) for one pool shape group."""
         key = (
             group_shape_key(group), bucket, self.params,
             self._query_widths(args),
         )
         fn = self._group_runner(group)
-        return self._compile_cached(key, lambda: fn.lower(group, *args))
+        return self._compile_cached(key, lambda: fn.lower(group, *args), phases)
 
     # -- request path -------------------------------------------------------
 
@@ -826,114 +832,103 @@ class HybridSearchService:
         (sealed segments) is never touched. Tombstones need no extra
         filtering here: both phases already filter on their own ``alive``
         masks."""
-        t0 = time.perf_counter()
-        traces0 = search_padded_trace_count()
-        grow_pass = search_padded if self.params.use_kg else scan_padded
-        gres = grow_pass(snap.grow, *args, self.params)
-        g_local = np.asarray(gres.ids)
-        gids_map = np.asarray(snap.grow_gids)
-        g_ids = np.where(
-            g_local >= 0,
-            gids_map[np.clip(g_local, 0, gids_map.shape[0] - 1)],
-            PAD_IDX,
-        )
-        g_scores = np.where(g_local >= 0, np.asarray(gres.scores), -np.inf)
-        g_ps = np.where(
-            (g_local >= 0)[:, :, None], np.asarray(gres.path_scores), 0.0
-        )
-        m_ids, m_scores, m_ps = self._merge_host(
-            [ids, g_ids],
-            [scores, g_scores],
-            ids.shape[1],
-            path_parts=[ps, g_ps],
-            spec=args[1],
-        )
-        if phases is not None:
-            phases.append((
-                "grow_merge", t0, time.perf_counter(),
-                {"grow_rows": int(snap.grow.n),
-                 "retraced": search_padded_trace_count() > traces0},
-            ))
+        with phase(
+            "grow_merge", self._m_phase, phases, grow_rows=int(snap.grow.n)
+        ) as attrs:
+            traces0 = search_padded_trace_count()
+            grow_pass = search_padded if self.params.use_kg else scan_padded
+            gres = grow_pass(snap.grow, *args, self.params)
+            g_local = np.asarray(gres.ids)
+            gids_map = np.asarray(snap.grow_gids)
+            g_ids = np.where(
+                g_local >= 0,
+                gids_map[np.clip(g_local, 0, gids_map.shape[0] - 1)],
+                PAD_IDX,
+            )
+            g_scores = np.where(g_local >= 0, np.asarray(gres.scores), -np.inf)
+            g_ps = np.where(
+                (g_local >= 0)[:, :, None], np.asarray(gres.path_scores), 0.0
+            )
+            m_ids, m_scores, m_ps = self._merge_host(
+                [ids, g_ids],
+                [scores, g_scores],
+                ids.shape[1],
+                path_parts=[ps, g_ps],
+                spec=args[1],
+            )
+            attrs["retraced"] = search_padded_trace_count() > traces0
         return m_ids, m_scores, m_ps, expanded + np.asarray(gres.expanded)
+
+    @staticmethod
+    def _read_back(results):
+        """Host copies of the results' ids, scores, path scores and
+        ``expanded``, one list per field (blocks on the device)."""
+        fields = ("ids", "scores", "path_scores", "expanded")
+        return [[np.asarray(getattr(r, f)) for r in results] for f in fields]
 
     def _run_pool(self, pool: SegmentPool, bucket: Bucket, args, phases=None):
         """Pool read: one cached executable per shape group, merged per-row
         in global-id space. Groups untouched by a compaction keep hitting
         their existing executables."""
-        t0 = time.perf_counter()
-        pairs = [
-            self._get_group_executable(group, bucket, args)
-            for group in pool.groups
-        ]
-        t1 = time.perf_counter()
+        with phase(
+            "executable_lookup", self._m_phase, phases, groups=len(pool.groups)
+        ) as attrs:
+            pairs = [
+                self._get_group_executable(group, bucket, args, phases)
+                for group in pool.groups
+            ]
+            attrs["hit"] = all(h for _, h in pairs)
         # dispatch EVERY group before blocking on any result: jax executes
         # asynchronously, so the groups' device work overlaps instead of
         # paying the sum of per-group latencies
-        results = []
-        for gi, (group, (exe, _)) in enumerate(zip(pool.groups, pairs)):
-            self._m_group_dispatch.inc(group=gi)
-            results.append(exe(group, *args))
-        ids_parts, score_parts, ps_parts = [], [], []
-        expanded = np.int64(0)
-        for res in results:
-            ids_parts.append(np.asarray(res.ids))
-            score_parts.append(np.asarray(res.scores))
-            ps_parts.append(np.asarray(res.path_scores))
-            expanded = expanded + np.asarray(res.expanded)
-        t2 = time.perf_counter()
-        if phases is not None:
-            phases.append((
-                "executable_lookup", t0, t1,
-                {"hit": all(h for _, h in pairs), "groups": len(pairs)},
-            ))
-            phases.append(
-                ("device_dispatch", t1, t2, {"groups": len(pairs)})
-            )
+        with phase("device_dispatch", self._m_phase, phases, groups=len(pairs)):
+            results = []
+            for gi, (group, (exe, _)) in enumerate(zip(pool.groups, pairs)):
+                self._m_group_dispatch.inc(group=gi)
+                results.append(exe(group, *args))
+        with phase("device_readback", self._m_phase, phases, groups=len(pairs)):
+            ids_parts, score_parts, ps_parts, exp_parts = self._read_back(results)
+        expanded = sum(exp_parts)
         if len(ids_parts) == 1:
             return ids_parts[0], score_parts[0], ps_parts[0], expanded
         k = ids_parts[0].shape[1]
-        m_ids, m_scores, m_ps = self._merge_host(
-            ids_parts, score_parts, k, path_parts=ps_parts, spec=args[1]
-        )
-        if phases is not None:
-            phases.append((
-                "fusion_rescore", t2, time.perf_counter(),
-                {"parts": len(ids_parts), "site": "pool_merge"},
-            ))
+        with phase(
+            "fusion_rescore", self._m_phase, phases,
+            parts=len(ids_parts), site="pool_merge",
+        ):
+            m_ids, m_scores, m_ps = self._merge_host(
+                ids_parts, score_parts, k, path_parts=ps_parts, spec=args[1]
+            )
         return m_ids, m_scores, m_ps, expanded
 
     def _run_batch(self, bucket: Bucket, entries) -> None:
         # batch phases are timed once and attributed to every query in the
         # batch: (name, t0, t1, attrs) tuples become spans on each request's
-        # TraceContext (DESIGN.md §12 span taxonomy)
+        # TraceContext, and each phase is a serving.<name> profiler span
+        # (DESIGN.md §12 span taxonomy)
         t_batch0 = time.perf_counter()
         blabel = _bucket_label(bucket)
         phases: list[tuple[str, float, float, dict]] = []
         try:
             snap = self._snap  # one snapshot for the whole batch
-            t0 = time.perf_counter()
-            args = self._assemble(bucket, entries)
-            phases.append((
-                "batch_assembly", t0, time.perf_counter(),
-                {"bucket": blabel, "requests": len(entries)},
-            ))
+            with phase(
+                "batch_assembly", self._m_phase, phases,
+                bucket=blabel, requests=len(entries),
+            ):
+                args = self._assemble(bucket, entries)
             if isinstance(snap.index, SegmentPool):
                 ids, scores, ps, expanded = self._run_pool(
                     snap.index, bucket, args, phases
                 )
             else:
-                t0 = time.perf_counter()
-                exe, hit = self._get_executable(snap, bucket, args)
-                t1 = time.perf_counter()
-                phases.append(("executable_lookup", t0, t1, {"hit": hit}))
-                res = exe(snap.index, *args)
-                ids = np.asarray(res.ids)
-                scores = np.asarray(res.scores)
-                ps = np.asarray(res.path_scores)
-                expanded = np.asarray(res.expanded)
-                phases.append(
-                    ("device_dispatch", t1, time.perf_counter(), {})
-                )
+                with phase("executable_lookup", self._m_phase, phases) as attrs:
+                    exe, attrs["hit"] = self._get_executable(
+                        snap, bucket, args, phases
+                    )
+                with phase("device_dispatch", self._m_phase, phases):
+                    res = exe(snap.index, *args)
+                with phase("device_readback", self._m_phase, phases):
+                    (ids,), (scores,), (ps,), (expanded,) = self._read_back([res])
             if snap.grow is not None:
                 ids, scores, ps, expanded = self._merge_grow(
                     snap, args, ids, scores, ps, expanded, phases
@@ -944,17 +939,19 @@ class HybridSearchService:
             for e in entries:
                 e.pending._fail(err)
             raise
-        for i, e in enumerate(entries):
-            e.pending._fulfill(
-                ids[i, : e.request.k],
-                scores[i, : e.request.k],
-                int(expanded[i]),
-                path_scores=ps[i, : e.request.k],
-            )
-        t_done = time.perf_counter()
+        with phase("deliver", self._m_phase, phases, requests=len(entries)):
+            for i, e in enumerate(entries):
+                e.pending._fulfill(
+                    ids[i, : e.request.k],
+                    scores[i, : e.request.k],
+                    int(expanded[i]),
+                    path_scores=ps[i, : e.request.k],
+                )
+            t_done = time.perf_counter()
+            for e in entries:
+                self._m_queue_wait.observe(t_batch0 - e.arrival_perf)
+                self._m_latency.observe(t_done - e.arrival_perf)
         for e in entries:
-            self._m_queue_wait.observe(t_batch0 - e.arrival_perf)
-            self._m_latency.observe(t_done - e.arrival_perf)
             ctx = e.request.trace
             if ctx is not None:
                 ctx.add_span(
@@ -962,7 +959,7 @@ class HybridSearchService:
                 )
                 for name, p0, p1, attrs in phases:
                     ctx.add_span(name, p0, p1, **attrs)
-        self._m_batch_exec.observe(t_done - t_batch0, bucket=blabel)
+        self._m_nodes_expanded.inc(int(expanded[: len(entries)].sum()))
         self.stats._batches.inc(bucket=blabel)
         self.stats._padded_slots.inc(bucket.batch - len(entries))
 
